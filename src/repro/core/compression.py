@@ -95,7 +95,7 @@ def activation_payload(x, quantize: bool = True) -> Payload:
     """Quantize (optional) + LZW; falls back to storing raw bytes whenever LZW
     would *expand* the payload (entropy coding loses on high-entropy data —
     a real transport sends raw in that case)."""
-    arr = np.asarray(x)
+    arr = np.asarray(x, dtype=np.float32)  # bf16 activations quantize in f32
     shape = arr.shape
     if quantize:
         scale = float(np.max(np.abs(arr))) or 1.0

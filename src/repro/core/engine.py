@@ -210,7 +210,7 @@ def device_forward(params: dict, cfg: vit_lib.ViTConfig, images: jax.Array,
                    schedule: Sequence[int], split: int, scores_fn=None):
     """Jdevice: embed + layers [0, split). Returns (x, sizes)."""
     x = vit_lib.embed_tokens(params, cfg, images)
-    sizes = jnp.ones(x.shape[:2], cfg.dtype)
+    sizes = vit_lib.initial_sizes(x)
     return vit_lib.run_blocks(params, cfg, x, sizes, schedule, 0, split, scores_fn=scores_fn)
 
 
@@ -222,19 +222,30 @@ def cloud_forward(params: dict, cfg: vit_lib.ViTConfig, x: jax.Array, sizes: jax
     return vit_lib.head_apply(params, cfg, x)
 
 
+_device_program = jax.jit(device_forward, static_argnums=(1, 3, 4, 5))
+_cloud_program = jax.jit(cloud_forward, static_argnums=(1, 4, 5, 6))
+
+
 def split_inference(params: dict, cfg: vit_lib.ViTConfig, images: jax.Array,
                     schedule: Sequence[int], split: int, *,
                     quantize: bool = False, scores_fn=None):
-    """Full Jdevice->wire->Jcloud round trip. Returns (logits, payload|None)."""
+    """Full Jdevice->wire->Jcloud round trip, unbatched and unpadded: each
+    partition is one jitted program and the codec runs on the host between
+    them. Returns (logits, payload|None).
+
+    Jitted, not op by op: in bf16 an op-by-op run rounds every intermediate
+    that a fused program keeps wider, and those ulps are enough to flip a
+    near-tied ToMe merge — a different, equally valid result."""
     n = cfg.n_layers
     split = min(max(split, 0), n + 1)
     s = n if split == n + 1 else split
-    x, sizes = device_forward(params, cfg, images, schedule, s, scores_fn=scores_fn)
+    schedule = tuple(int(r) for r in schedule)
+    x, sizes = _device_program(params, cfg, images, schedule, s, scores_fn)
     payload = None
     if split not in (0, n + 1):
         payload = compression.activation_payload(x, quantize=quantize)
         x = jnp.asarray(compression.decode_activation(payload), dtype=cfg.dtype)
-    logits = cloud_forward(params, cfg, x, sizes, schedule, s, scores_fn=scores_fn)
+    logits = _cloud_program(params, cfg, x, sizes, schedule, s, scores_fn)
     return logits, payload
 
 
@@ -511,20 +522,27 @@ class JanusEngine:
         raise ValueError(policy)
 
     # -- real-math execution (compiled-plan cache) ---------------------------
-    def _execute_device(self, dec: Decision, images: jax.Array) -> tuple[ExecPlan, float | None]:
-        """Run the device partition (compiled) and encode the wire payload.
-        The timing plane may model a bigger ViT than the executed one —
-        (alpha, split) is remapped onto the executed geometry. Returns the
-        pending ExecPlan and the measured payload size (None = no transfer)."""
-        n_exec = self.model_cfg.n_layers
+    def _exec_geometry(self, dec: Decision) -> tuple[tuple[int, ...], int]:
+        """(merge schedule, split) to execute for a decision priced on the
+        timing profile: the decision itself when the executed model is the
+        profiled one (the served ViT-L@384); a smaller stand-in (CPU
+        rehearsal) gets (alpha, split) remapped onto its geometry."""
+        cfg, p = self.model_cfg, self.profile
+        if (cfg.n_layers, cfg.num_tokens) == (p.n_layers, p.x0):
+            return tuple(dec.schedule), dec.split
+        n_exec = cfg.n_layers
         sched_exec = tuple(pruning.make_schedule(
-            self.profile.schedule_kind, dec.alpha, n_exec,
-            self.model_cfg.num_tokens))
-        n_prof = self.profile.n_layers
-        if dec.split >= n_prof + 1:
-            split_exec = n_exec + 1
-        else:
-            split_exec = min(round(dec.split * n_exec / n_prof), n_exec)
+            p.schedule_kind, dec.alpha, n_exec, cfg.num_tokens))
+        if dec.split >= p.n_layers + 1:
+            return sched_exec, n_exec + 1
+        return sched_exec, min(round(dec.split * n_exec / p.n_layers), n_exec)
+
+    def execute_device(self, dec: Decision, images: jax.Array) -> tuple[ExecPlan, float | None]:
+        """Run the device partition (compiled) and encode the wire payload.
+        Returns the pending ExecPlan and the measured payload size (None = no
+        transfer)."""
+        n_exec = self.model_cfg.n_layers
+        sched_exec, split_exec = self._exec_geometry(dec)
         s = n_exec if split_exec == n_exec + 1 else split_exec
         dev_fn = self.plan_cache.device_fn(self.model_cfg, sched_exec, s, images)
         x, sizes = dev_fn(self.params, images)
@@ -562,7 +580,7 @@ class JanusEngine:
         payload_bytes = self._payload_bytes(counts, dec.split)
         exec_plan = None
         if self.cfg.execute and self.params is not None and images is not None:
-            exec_plan, measured = self._execute_device(dec, images)
+            exec_plan, measured = self.execute_device(dec, images)
             if measured is not None:
                 payload_bytes = measured
             n_exec = self.model_cfg.n_layers

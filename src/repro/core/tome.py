@@ -72,7 +72,9 @@ def _merge_one(x, sizes, src_idx, unm_idx, dst_idx):
     # guard the divisor: real tokens always have sb_new >= 1 (bitwise no-op),
     # but padded execution (tome_merge_padded) carries size-0 pad tokens whose
     # 0/0 would otherwise mint NaNs that poison downstream attention
-    dst = b_new / jnp.maximum(sb_new, 1e-30)[:, None]
+    # f32 sizes promote the weighted sums; the merged values return to x's
+    # dtype so a bf16 model stays bf16
+    dst = (b_new / jnp.maximum(sb_new, 1e-30)[:, None]).astype(x.dtype)
     unm = jnp.take(a, unm_idx, axis=0)
     s_unm = jnp.take(sa, unm_idx, axis=0)
     return jnp.concatenate([unm, dst], axis=0), jnp.concatenate([s_unm, sb_new], axis=0)

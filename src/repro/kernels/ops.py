@@ -1,9 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU the kernels compile natively (``interpret=False``); on this CPU
-container they run in interpret mode for correctness, and the *models* default
-to the pure-jnp path (``ref``/layers math) so the dry-run roofline reflects the
-XLA program. ``use_kernels()`` flips model hot-spots to the Pallas path.
+The raw kernels compile for the TPU by default (``interpret=False``). These
+wrappers choose for the caller: ``interpret`` is set exactly when JAX's
+default backend is not a TPU, so on a CPU they run the Pallas interpreter
+(correctness only, never a timing). ``use_pallas=False`` selects the
+pure-jnp oracle in ``ref.py``. No model calls these wrappers yet: the
+served ViT runs jnp attention and jnp ToMe.
 
 Every wrapper keeps the oracle's exact signature so tests can sweep
 shapes/dtypes with assert_allclose against ref.py.

@@ -2,8 +2,13 @@
 
 Drives the full Janus stack — profiler fit, dynamic scheduler, collaborative
 split execution with LZW transport, over a synthetic dynamic network trace —
-with REAL model math on a reduced ViT (CPU) and paper-calibrated platform
-latency models for the timing plane.
+with paper-calibrated platform latency models for the timing plane.
+``--execute`` adds the real model math: the paper's ViT-L@384 in bf16 (the
+model the timing plane prices), which wants a TPU; ``--execute --smoke-model``
+runs a 4-layer, 64-wide f32 stand-in instead, for CPU rehearsal and tests.
+The CLI keeps JAX's persistent compilation cache in
+``$JAX_COMPILATION_CACHE_DIR`` when set, else in ``<repo>/.jax_cache``
+(``enable_compile_cache``); ``main()`` called as a library leaves it alone.
 
 Single stream (the paper's §V-B setting — policy comparison table):
 
@@ -77,8 +82,9 @@ pricing the bucketed ``--execute`` path actually runs (see
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 import time
-
 
 import jax
 
@@ -92,6 +98,23 @@ from repro.serving import fleet as fleet_lib
 from repro.serving import sla as sla_lib
 from repro.serving import telemetry as telemetry_lib
 from repro.serving import workload as workload_lib
+
+
+# the compile cache's fixed home, never derived from a tmpdir, a pid or the
+# time, so that a later run finds what an earlier one compiled
+_REPO_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+    ``$JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    left alone; otherwise the cache lives at the fixed, git-ignored
+    ``<repo>/.jax_cache``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(_REPO_CACHE_DIR))
+    return str(_REPO_CACHE_DIR)
 
 
 def make_profile(cfg: vit_lib.ViTConfig, sla_note: str = "") -> scheduler.ModelProfile:
@@ -190,9 +213,9 @@ def spec_from_args(args) -> workload_lib.WorkloadSpec:
         faults=_faults_from_args(args))
 
 
-def run_fleet(args, profile, eng_cfg, model_cfg=None, params=None, images=None):
-    """Fleet mode: a workload scenario through one shared cloud tier."""
-    spec = spec_from_args(args)
+def build_fleet(args, spec, profile, eng_cfg, model_cfg=None, params=None):
+    """The fleet runtime for ``spec`` through one shared cloud tier, with
+    ``--bucket-edges`` / ``--mesh`` applied to real execution."""
     bucketing = mesh_rules = None
     if args.execute and args.bucket_edges > 0:
         bucketing = bucketing_lib.BucketingConfig(n_edges=args.bucket_edges)
@@ -201,10 +224,16 @@ def run_fleet(args, profile, eng_cfg, model_cfg=None, params=None, images=None):
         from repro.sharding.rules import make_rules
         mesh_rules = make_rules(args.mesh, make_host_mesh(
             model=args.mesh_model))
-    rt = workload_lib.build_runtime(spec, profile, eng_cfg,
-                                    model_cfg=model_cfg, params=params,
-                                    bucketing=bucketing,
-                                    mesh_rules=mesh_rules)
+    return workload_lib.build_runtime(spec, profile, eng_cfg,
+                                      model_cfg=model_cfg, params=params,
+                                      bucketing=bucketing,
+                                      mesh_rules=mesh_rules)
+
+
+def run_fleet(args, profile, eng_cfg, model_cfg=None, params=None, images=None):
+    """Fleet mode: run ``build_fleet``'s runtime and print its report."""
+    spec = spec_from_args(args)
+    rt = build_fleet(args, spec, profile, eng_cfg, model_cfg, params)
     cloud = rt.cloud
     tel = None
     if args.telemetry_sample > 0:
@@ -314,7 +343,32 @@ def run_fleet(args, profile, eng_cfg, model_cfg=None, params=None, images=None):
     return fs
 
 
-def main(argv=None):
+def setup(args):
+    """The timing plane for the paper's ViT-L@384 and, under ``--execute``,
+    the executed model with seeded random weights and one seeded frame:
+    ``(profile, eng_cfg, model_cfg, params, images)``."""
+    paper = get_arch("janus-vit-l384")
+    profile = make_profile(paper.config)
+    planner_cfg = planner.PlannerConfig(t=args.alpha_step,
+                                        k=args.split_spacing)
+    if args.step_planner > 0:
+        profile = planner.step_aware_profile(
+            profile, bucketing_lib.BucketingConfig(n_edges=args.step_planner),
+            planner_cfg)
+    params = model_cfg = images = None
+    if args.execute:
+        model_cfg = paper.smoke_config if args.smoke_model else paper.config
+        params = param_lib.init_params(vit_lib.specs(model_cfg),
+                                       jax.random.key(0), dtype=model_cfg.dtype)
+        images = jax.random.normal(jax.random.key(1),
+                                   (1, model_cfg.img_res, model_cfg.img_res, 3))
+    eng_cfg = engine.EngineConfig(sla_s=args.sla_ms / 1e3, execute=args.execute,
+                                  planner=args.planner,
+                                  planner_cfg=planner_cfg)
+    return profile, eng_cfg, model_cfg, params, images
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--network", default="4g", choices=["4g", "5g", "wifi"])
     ap.add_argument("--mobility", default="driving",
@@ -323,7 +377,11 @@ def main(argv=None):
     ap.add_argument("--sla-ms", type=float, default=300.0)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--execute", action="store_true",
-                    help="run real split-model math on a reduced ViT")
+                    help="run the real split-model math at the paper's "
+                         "ViT-L@384 width in bf16")
+    ap.add_argument("--smoke-model", action="store_true",
+                    help="with --execute: run a 4-layer, 64-wide f32 "
+                         "stand-in instead (CPU rehearsal and tests)")
     ap.add_argument("--bucket-edges", type=int, default=0,
                     help="with --execute: bucket cloud-partition token "
                          "counts to at most N edges per split so mixed-α "
@@ -460,6 +518,11 @@ def main(argv=None):
     ap.add_argument("--split-spacing", type=int, default=5,
                     help="fine-to-coarse split-candidate spacing k "
                          "(PlannerConfig.k)")
+    return ap
+
+
+def main(argv=None):
+    ap = build_parser()
     args = ap.parse_args(argv)
 
     if args.streams <= 0 and not args.workload:
@@ -482,15 +545,11 @@ def main(argv=None):
             ap.error(f"{' '.join(fleet_only)} only work in fleet mode "
                      "(--streams N or --workload spec.json)")
 
-    paper = get_arch("janus-vit-l384")
-    cfg_timing = paper.config          # timing plane: the paper's ViT-L@384
-    profile = make_profile(cfg_timing)
-    planner_cfg = planner.PlannerConfig(t=args.alpha_step,
-                                        k=args.split_spacing)
-    if args.step_planner > 0:
-        profile = planner.step_aware_profile(
-            profile, bucketing_lib.BucketingConfig(n_edges=args.step_planner),
-            planner_cfg)
+    if args.smoke_model and not args.execute:
+        ap.error("--smoke-model only applies with --execute")
+
+    profile, eng_cfg, model_cfg, params, images = setup(args)
+    planner_cfg = eng_cfg.planner_cfg
     tables = planner.tables_for(profile, planner_cfg)
     if args.planner == "legacy":  # measure the implementation actually used
         dec = scheduler._reference_schedule(profile, 10e6, 0.02,
@@ -505,16 +564,6 @@ def main(argv=None):
           f"splits={len(tables.candidates)} "
           f"decide={dec.scheduler_overhead_s*1e6:.0f}us/frame")
 
-    params = model_cfg = images = None
-    if args.execute:
-        model_cfg = paper.smoke_config
-        params = param_lib.init_params(vit_lib.specs(model_cfg), jax.random.key(0))
-        images = jax.random.normal(jax.random.key(1),
-                                   (1, model_cfg.img_res, model_cfg.img_res, 3))
-
-    eng_cfg = engine.EngineConfig(sla_s=args.sla_ms / 1e3, execute=args.execute,
-                                  planner=args.planner,
-                                  planner_cfg=planner_cfg)
     if args.streams > 0 or args.workload:
         run_fleet(args, profile, eng_cfg, model_cfg=model_cfg, params=params,
                   images=images)
@@ -541,4 +590,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

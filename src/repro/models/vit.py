@@ -96,6 +96,13 @@ def embed_tokens(params: dict, cfg: ViTConfig, images: jax.Array) -> jax.Array:
     return x + params["pos"].astype(x.dtype)
 
 
+def initial_sizes(x: jax.Array) -> jax.Array:
+    """ToMe token sizes [B, N], one patch per token. Always float32, whatever
+    the model dtype: bf16 holds integers exactly only up to 256, and merged
+    sizes are summed and fed to ``log`` for proportional attention."""
+    return jnp.ones(x.shape[:2], jnp.float32)
+
+
 def _block(bp: dict, cfg: ViTConfig, x: jax.Array, sizes: jax.Array | None = None,
            merge_r: int = 0, scores_fn=None):
     bias = None
@@ -211,7 +218,7 @@ def forward_janus(params: dict, cfg: ViTConfig, images: jax.Array,
     monolithic run for any s.
     """
     x = embed_tokens(params, cfg, images)
-    sizes = jnp.ones(x.shape[:2], cfg.dtype)
+    sizes = initial_sizes(x)
     x, sizes = run_blocks(params, cfg, x, sizes, schedule, 0, cfg.n_layers, scores_fn=scores_fn)
     return head_apply(params, cfg, x)
 

@@ -47,7 +47,7 @@ billing on, the vectorized path bills the *amortized* measured wall time of
 the batched eval per decision (the retired loop billed each decision's own
 measured wall time — equally wall-clock-dependent, differently sliced).
 
-The engine-backed slow path (``execute=True`` with images, or
+The engine-backed slow path (``execute=True``, which needs images, or
 ``planner="legacy"``) runs the same event machinery with per-frame
 ``plan_frame`` calls, so real-math micro-batched cloud execution and the
 legacy-planner comparison benches keep their exact semantics. The fallback
@@ -577,7 +577,11 @@ def simulate(rt, images=None, record: list | None = None, telemetry=None):
 
     streams, cloud = rt.streams, rt.cloud
     n_streams = len(streams)
-    engine_mode = (rt._execute and images is not None) or \
+    if rt._execute and images is None:
+        raise ValueError(
+            "an execute runtime needs images: without them it would "
+            "simulate in place of running the model")
+    engine_mode = rt._execute or \
         any(e.cfg.planner == "legacy" for e in rt.engines)
     pipes = [None] * n_streams if engine_mode else _build_pipes(rt)
     fm = None
@@ -585,7 +589,7 @@ def simulate(rt, images=None, record: list | None = None, telemetry=None):
         if engine_mode:
             raise ValueError(
                 "fault injection requires the vectorized planner path "
-                "(incompatible with execute-with-images and planner='legacy')")
+                "(incompatible with execute and planner='legacy')")
         if any(p is None for p in pipes):
             raise ValueError(
                 "fault injection requires in-order arrival times "
@@ -875,7 +879,7 @@ def simulate(rt, images=None, record: list | None = None, telemetry=None):
 
     def dispatch(r: int, batch: list[Request], now: float) -> None:
         members = [req.rid for req in batch]
-        if rt._execute and engine_mode:
+        if rt._execute:
             run_cloud_batch(rt.plan_cache, rt.model_cfg, rt.params,
                             [exec_plans[rid] for rid in members],
                             buckets=rt.buckets)

@@ -1,4 +1,7 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracles."""
+"""Per-kernel shape/dtype sweeps: Pallas in interpret mode vs pure-jnp oracles.
+
+The raw kernel wrappers compile for the TPU by default; every call here
+passes ``interpret=True`` so the sweep runs on the CPU."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -24,7 +27,7 @@ def test_tome_scores_matches_ref(b, na, nb, d, dtype):
     bb = _randn((b, nb, d), dtype)
     a = a / jnp.linalg.norm(a.astype(jnp.float32), axis=-1, keepdims=True).astype(dtype)
     bb = bb / jnp.linalg.norm(bb.astype(jnp.float32), axis=-1, keepdims=True).astype(dtype)
-    m, i = tome_scores(a, bb, bm=64, bn=64)
+    m, i = tome_scores(a, bb, bm=64, bn=64, interpret=True)
     mr, ir = ref.tome_scores_ref(a, bb)
     atol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(m), np.asarray(mr), atol=atol, rtol=1e-3)
@@ -45,7 +48,7 @@ def test_flash_attention_matches_ref(b, h, sq, sk, d, causal):
     q = _randn((b, h, sq, d), jnp.float32)
     k = _randn((b, h, sk, d), jnp.float32)
     v = _randn((b, h, sk, d), jnp.float32)
-    out = flash_attention(q, k, v, causal=causal, bq=64, bk=64)
+    out = flash_attention(q, k, v, causal=causal, bq=64, bk=64, interpret=True)
     expected = ref.flash_attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                atol=2e-5, rtol=1e-4)
@@ -63,7 +66,7 @@ def test_flash_attention_key_bias_matches_ref(b, h, s, d):
                       jnp.asarray(1.0 + RNG.uniform(size=(b, s)), jnp.float32),
                       0.0)
     bias = jnp.log(sizes)  # -inf on the padded tail
-    out = flash_attention(q, k, v, bias=bias, bq=64, bk=64)
+    out = flash_attention(q, k, v, bias=bias, bq=64, bk=64, interpret=True)
     expected = ref.flash_attention_ref(q, k, v, bias=bias)
     assert not np.any(np.isnan(np.asarray(out)))
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
@@ -78,7 +81,7 @@ def test_flash_attention_kv_len_equals_truncation(b, h, s, d):
     k = _randn((b, h, s, d), jnp.float32)
     v = _randn((b, h, s, d), jnp.float32)
     kv_len = jnp.asarray([s, s - 9, s // 2][:b], jnp.int32)
-    out = flash_attention(q, k, v, kv_len=kv_len, bq=64, bk=64)
+    out = flash_attention(q, k, v, kv_len=kv_len, bq=64, bk=64, interpret=True)
     expected = ref.flash_attention_ref(q, k, v, kv_len=kv_len)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                atol=2e-5, rtol=1e-4)
@@ -94,7 +97,7 @@ def test_flash_attention_bf16():
     q = _randn((1, 2, 128, 64), jnp.bfloat16)
     k = _randn((1, 2, 128, 64), jnp.bfloat16)
     v = _randn((1, 2, 128, 64), jnp.bfloat16)
-    out = flash_attention(q, k, v, bq=64, bk=64)
+    out = flash_attention(q, k, v, bq=64, bk=64, interpret=True)
     expected = ref.flash_attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expected, np.float32), atol=3e-2)
@@ -108,7 +111,7 @@ def test_decode_attention_matches_ref(b, hq, hkv, s, d, length):
     q = _randn((b, hq, d), jnp.float32)
     k = _randn((b, s, hkv, d), jnp.float32)
     v = _randn((b, s, hkv, d), jnp.float32)
-    out = decode_attention(q, k, v, jnp.int32(length), bs=128)
+    out = decode_attention(q, k, v, jnp.int32(length), bs=128, interpret=True)
     expected = ref.decode_attention_ref(q, k, v, jnp.int32(length))
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                atol=2e-5, rtol=1e-4)

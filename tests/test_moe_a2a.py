@@ -31,9 +31,10 @@ A2A_SCRIPT = textwrap.dedent("""
     import sys
     sys.path.insert(0, {src!r})
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_host_mesh
     from repro.models import moe, moe_a2a, param
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(model=4)   # (2, 4) over the 8 host devices
     cfg = moe.MoEConfig(d_model=32, d_ff=16, n_experts=8, top_k=2,
                         capacity_factor=8.0, group_size=32)
     params = param.init_params(moe.specs(cfg), jax.random.key(0))

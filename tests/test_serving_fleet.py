@@ -258,6 +258,40 @@ def test_fleet_batched_execute_logits_equal_per_item():
                                        atol=1e-5, rtol=1e-5)
 
 
+def test_execute_fleet_without_images_raises():
+    """An execute runtime given no images refuses to run rather than quietly
+    simulating without the model."""
+    cfg, params, _, eng_cfg = _exec_setup()
+    trace = bandwidth.NetworkTrace(np.full(2, 80e6), 0.002, "s0")
+    rt = fleet.FleetRuntime(_profile(), eng_cfg, [fleet.StreamSpec(trace, 2)],
+                            model_cfg=cfg, params=params)
+    with pytest.raises(ValueError, match="needs images"):
+        rt.run()
+
+
+def test_execute_at_profiled_geometry_runs_the_decision():
+    """When the executed model has the profiled geometry (as the served
+    ViT-L@384 does), the executed plan is the decision itself — baseline
+    policies included, whose fixed ToMe schedule their alpha=0 does not
+    encode."""
+    prof = _profile()
+    cfg = vit_lib.ViTConfig(img_res=48, patch=4, n_layers=prof.n_layers,
+                            d_model=32, n_heads=2, d_ff=64, n_classes=8)
+    assert cfg.num_tokens == prof.x0
+    params = param_lib.init_params(vit_lib.specs(cfg), jax.random.key(0))
+    images = jax.random.normal(jax.random.key(1), (1, 48, 48, 3))
+    eng = engine.JanusEngine(prof, engine.EngineConfig(sla_s=0.5, execute=True),
+                             model_cfg=cfg, params=params)
+    trace = bandwidth.NetworkTrace(np.full(2, 80e6), 0.002, "s0")
+    for policy in ("janus", "cloud"):
+        step = eng.plan_frame(0, trace, policy,
+                              bandwidth.HarmonicMeanEstimator(cold_start_bps=80e6),
+                              images=images)
+        assert step.exec_plan.schedule == tuple(step.decision.schedule)
+        assert step.exec_plan.split == step.decision.split
+        assert step.exec_plan.logits.shape == (1, cfg.n_classes)
+
+
 def test_fleet_shares_one_plan_cache_across_streams():
     """Same-geometry streams compile each partition program once fleet-wide:
     the shared cache traces exactly (device + cloud) once."""
