@@ -1,0 +1,8 @@
+"""Device: share of the traced window in which no op ran on the chip,
+averaged over the chips used (%)."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * run.trace.idle_share
