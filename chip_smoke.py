@@ -38,6 +38,9 @@ import jax.numpy as jnp
 import numpy as np
 
 ROOT = pathlib.Path(__file__).resolve().parent
+sys.path.insert(0, str(ROOT / "bench"))
+
+from compile_counter import CompileCounter  # noqa: E402
 
 # 4 streams x 3 frames on fixed 4G/static traces. On the paper's timing
 # profile the planner picks alpha=0.27 at split 0 for most frames (ToMe
@@ -93,33 +96,11 @@ def check(ok: bool, what: str) -> None:
         raise CheckFailed(what)
 
 
-class CompileCounter:
-    """Counts XLA backend compiles and persistent-cache hits, via
-    ``jax.monitoring``."""
-
-    def __init__(self):
-        self.compiles, self.seconds, self.cache_hits = 0, 0.0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def phase(self, name: str, since: tuple) -> tuple:
-        c, s, h = self.compiles - since[0], self.seconds - since[1], \
-            self.cache_hits - since[2]
-        print(f"[chip_smoke] {name}: compiles={c} compile_s={s:.3f} "
-              f"cache_hits={h}")
-        return self.snapshot()
-
-    def snapshot(self) -> tuple:
-        return self.compiles, self.seconds, self.cache_hits
+def phase(counter: CompileCounter, name: str, snap: tuple) -> tuple:
+    """Print the programs built since ``snap``: compiled apart from loaded
+    from the persistent cache. Returns a new snapshot."""
+    print(f"[chip_smoke] {name}: {counter.since(snap)}")
+    return counter.snapshot()
 
 
 def rel_err(x, ref) -> float:
@@ -170,13 +151,13 @@ def one_chip(counter: CompileCounter) -> None:
     check((n, cfg.num_tokens) == (profile.n_layers, profile.x0),
           f"executed model is the priced one ({n} layers, "
           f"{cfg.num_tokens} tokens, d={cfg.d_model}, {cfg.dtype.__name__})")
-    snap = counter.phase("setup", snap)
+    snap = phase(counter, "setup", snap)
 
     # the served fleet path, cold (compiling) then warm
     runs = []
     for name in ("fleet cold", "fleet warm"):
         runs.append(timed_run(rt, images, name))
-        snap = counter.phase(name, snap)
+        snap = phase(counter, name, snap)
         check_logits(runs[-1].all_frames, cfg.n_classes, name)
     # (alpha, split, logits) of every served frame; the executed schedule
     # of a janus decision is the planner's for its alpha
@@ -202,12 +183,12 @@ def one_chip(counter: CompileCounter) -> None:
           f"bucket_edge={rt.buckets.edge_for(s, count)} "
           f"payload_bytes={payloads[0]} members={len(plans)} "
           f"wall_s={time.perf_counter() - t0:.3f}")
-    snap = counter.phase("interior", snap)
+    snap = phase(counter, "interior", snap)
     check_logits(plans, cfg.n_classes, "interior")
     served += [(alpha, s, p.logits) for p in plans]
     pc = rt.plan_cache
     print(f"[chip_smoke] plan_cache traces={dict(pc.traces_by_kind)} "
-          f"hits={pc.hits} misses={pc.misses}")
+          f"build_s={pc.build_s:.3f} hits={pc.hits} misses={pc.misses}")
 
     # (a) served logits vs the op-by-op split round trip
     refs, errs = {}, {}
@@ -226,7 +207,7 @@ def one_chip(counter: CompileCounter) -> None:
           f"max_rel_l2 per (alpha, split)="
           f"{ {k: f'{v:.3e}' for k, v in errs.items()} } "
           f"wall_s={time.perf_counter() - t0:.3f}")
-    snap = counter.phase("reference (a)", snap)
+    snap = phase(counter, "reference (a)", snap)
     check(worst <= TOL_SERVED_VS_SPLIT,
           f"(a) max rel L2 {worst:.3e} <= {TOL_SERVED_VS_SPLIT}")
 
@@ -240,7 +221,7 @@ def one_chip(counter: CompileCounter) -> None:
         ref32 = fwd(params32, cfg32, images, no_merge)
     err = rel_err(out, ref32)
     print(f"[chip_smoke] (b) alpha=0 bf16 vs f32: rel_l2={err:.3e}")
-    counter.phase("reference (b)", snap)
+    phase(counter, "reference (b)", snap)
     check(err <= TOL_BF16_VS_F32, f"(b) rel L2 {err:.3e} <= {TOL_BF16_VS_F32}")
 
 
@@ -249,7 +230,7 @@ def four_chips(counter: CompileCounter) -> None:
     check(len(jax.devices()) == 4, f"four devices (found {len(jax.devices())})")
     rt_mesh, cfg, images = build(FOUR_CHIP_ARGV + ["--mesh", "dp"])
     fs_mesh = timed_run(rt_mesh, images, "fleet dp mesh")
-    snap = counter.phase("fleet dp mesh", snap)
+    snap = phase(counter, "fleet dp mesh", snap)
     rules = rt_mesh.mesh_rules
     print(f"[chip_smoke] mesh={dict(rules.mesh.shape)} "
           f"fallbacks={rules.fallbacks}")
@@ -261,7 +242,7 @@ def four_chips(counter: CompileCounter) -> None:
 
     rt_one, _, _ = build(FOUR_CHIP_ARGV)
     fs_one = timed_run(rt_one, images, "fleet one chip")
-    counter.phase("fleet one chip", snap)
+    phase(counter, "fleet one chip", snap)
     pairs = list(zip(fs_mesh.all_frames, fs_one.all_frames, strict=True))
     check(all((fm.alpha, fm.split) == (fo.alpha, fo.split)
               for fm, fo in pairs),
@@ -305,8 +286,7 @@ def main(argv=None) -> int:
         return 1
     stats = devices[0].memory_stats() or {}
     print(f"[chip_smoke] peak_bytes_in_use={stats.get('peak_bytes_in_use')} "
-          f"total_compiles={counter.compiles} "
-          f"total_compile_s={counter.seconds:.3f} "
+          f"programs={counter.since((0, 0.0, 0))} "
           f"total_wall_s={time.perf_counter() - t0:.3f}")
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,

@@ -47,6 +47,7 @@ cloud partitions execute batched at micro-batch dispatch time.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Callable, Sequence
 
 import jax
@@ -59,6 +60,7 @@ from repro.core.bandwidth import HarmonicMeanEstimator, NetworkTrace
 from repro.core.pruning import AccuracyModel
 from repro.core.scheduler import Decision, ModelProfile
 from repro.models import vit as vit_lib
+from repro.runtime import spans
 from repro.sharding import rules as rules_lib
 
 
@@ -137,6 +139,7 @@ class ExecPlan:
     x: Any = None
     sizes: Any = None
     logits: Any = None
+    frame_id: tuple[int, int] | None = None   # (stream, frame), for spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -260,6 +263,11 @@ class CompiledPlanCache:
     "second frame with the same geometry does not retrace";
     ``traces_by_kind`` splits the same counter per partition program so the
     execute bench can bound *cloud* retraces by the bucket-table cell count.
+    ``build_s`` sums the wall seconds of each new program's first call
+    (trace, lower, compile or load from the persistent cache, dispatch),
+    each under a ``janus/program_build`` span. The jitted functions are
+    named ``janus_device``, ``janus_cloud`` and ``janus_cloud_padded``, so
+    XLA names their programs ``jit_janus_*``.
 
     ``rules`` (optional ``sharding.Rules``) makes every compiled partition
     mesh-aware: the partition programs trace under ``use_rules``, so the
@@ -277,6 +285,7 @@ class CompiledPlanCache:
         self.misses = 0
         self.traces = 0
         self.traces_by_kind: dict[str, int] = {}
+        self.build_s = 0.0
 
     def _bump(self, kind: str) -> None:
         self.traces += 1
@@ -286,10 +295,23 @@ class CompiledPlanCache:
         fn = self._fns.get(key)
         if fn is None:
             self.misses += 1
-            fn = self._fns[key] = build()
+            fn = self._fns[key] = self._first_call(key, build())
         else:
             self.hits += 1
         return fn
+
+    def _first_call(self, key: tuple, fn: Callable) -> Callable:
+        """``fn`` behind a one-shot wrapper that times its first call into
+        ``build_s`` and then puts ``fn`` itself in its place: a hit calls
+        the bare jitted function."""
+        def first(*args):
+            t0 = time.perf_counter()
+            with spans.span(spans.PROGRAM_BUILD, kind=key[0]):
+                out = fn(*args)
+            self.build_s += time.perf_counter() - t0
+            self._fns[key] = fn
+            return out
+        return first
 
     @staticmethod
     def _shape_key(arr) -> tuple:
@@ -300,11 +322,11 @@ class CompiledPlanCache:
         key = ("device", cfg, schedule, split, self._shape_key(images))
 
         def build():
-            def traced(params, images):
+            def janus_device(params, images):
                 self._bump("device")
                 with rules_lib.use_rules(self.rules):
                     return device_forward(params, cfg, images, schedule, split)
-            return jax.jit(traced)
+            return jax.jit(janus_device)
 
         return self._get(key, build)
 
@@ -313,11 +335,11 @@ class CompiledPlanCache:
         key = ("cloud", cfg, schedule, split, self._shape_key(x))
 
         def build():
-            def traced(params, x, sizes):
+            def janus_cloud(params, x, sizes):
                 self._bump("cloud")
                 with rules_lib.use_rules(self.rules):
                     return cloud_forward(params, cfg, x, sizes, schedule, split)
-            return jax.jit(traced)
+            return jax.jit(janus_cloud)
 
         return self._get(key, build)
 
@@ -331,13 +353,13 @@ class CompiledPlanCache:
         def build():
             schedule = (0,) * split + tuple(suffix)
 
-            def traced(params, x, sizes):
+            def janus_cloud_padded(params, x, sizes):
                 self._bump("cloud_padded")
                 with rules_lib.use_rules(self.rules):
                     x2, _ = vit_lib.run_blocks_padded(
                         params, cfg, x, sizes, schedule, split, cfg.n_layers)
                     return vit_lib.head_apply(params, cfg, x2)
-            return jax.jit(traced)
+            return jax.jit(janus_cloud_padded)
 
         return self._get(key, build)
 
@@ -370,48 +392,64 @@ def run_cloud_batch(cache: CompiledPlanCache, cfg: vit_lib.ViTConfig,
     and retraces are bounded by the table's (split, edge) cell count instead
     of the number of distinct α in flight.
     """
+    pending = [p for p in plans if p is not None and p.logits is None]
+    with spans.span(spans.CLOUD_BATCH, frames=len(pending),
+                    ids=lambda: _frame_ids(pending)):
+        _run_cloud_batch(cache, cfg, params, pending, buckets)
+
+
+def _frame_ids(plans: Sequence[ExecPlan]) -> str:
+    return ",".join(f"{p.frame_id[0]}:{p.frame_id[1]}" for p in plans
+                    if p.frame_id is not None)
+
+
+def _run_cloud_batch(cache: CompiledPlanCache, cfg: vit_lib.ViTConfig,
+                     params: dict, plans: Sequence[ExecPlan],
+                     buckets: BucketTable | None) -> None:
     n = cfg.n_layers
     groups: dict[tuple, list[ExecPlan]] = {}
-    for plan in plans:
-        if plan is None or plan.logits is not None:
-            continue
-        s = n if plan.split == n + 1 else plan.split
-        if buckets is None:
-            key = (plan.schedule, s, tuple(plan.x.shape[1:]), str(plan.x.dtype))
-        else:
-            edge = buckets.edge_for(s, plan.x.shape[1])
-            key = (plan.schedule[s:], s, edge, plan.x.shape[2],
-                   str(plan.x.dtype))
-        groups.setdefault(key, []).append(plan)
+    with spans.span(spans.CLOUD_ASSEMBLE):
+        for plan in plans:
+            s = n if plan.split == n + 1 else plan.split
+            if buckets is None:
+                key = (plan.schedule, s, tuple(plan.x.shape[1:]), str(plan.x.dtype))
+            else:
+                edge = buckets.edge_for(s, plan.x.shape[1])
+                key = (plan.schedule[s:], s, edge, plan.x.shape[2],
+                       str(plan.x.dtype))
+            groups.setdefault(key, []).append(plan)
     for key, members in groups.items():
-        if buckets is None:
-            schedule, s = key[0], key[1]
-            x = jnp.concatenate([m.x for m in members], axis=0)
-            sizes = jnp.concatenate([m.sizes for m in members], axis=0)
-            fn = cache.cloud_fn(cfg, schedule, s, x)
-        else:
-            suffix, s, edge = key[0], key[1], key[2]
-            # pad once per distinct token count, not once per member: the
-            # eager pad/concat dispatches then scale with the handful of
-            # distinct counts in flight instead of the fleet size
-            by_count: dict[int, list[ExecPlan]] = {}
-            for m in members:
-                by_count.setdefault(m.x.shape[1], []).append(m)
-            chunks, members = [], []
-            for t in sorted(by_count):
-                ms = by_count[t]
-                cx = ms[0].x if len(ms) == 1 else \
-                    jnp.concatenate([m.x for m in ms], axis=0)
-                cs = ms[0].sizes if len(ms) == 1 else \
-                    jnp.concatenate([m.sizes for m in ms], axis=0)
-                chunks.append(_pad_tokens(cx, cs, edge))
-                members.extend(ms)
-            x = chunks[0][0] if len(chunks) == 1 else \
-                jnp.concatenate([c[0] for c in chunks], axis=0)
-            sizes = chunks[0][1] if len(chunks) == 1 else \
-                jnp.concatenate([c[1] for c in chunks], axis=0)
-            fn = cache.cloud_padded_fn(cfg, suffix, s, x)
-        logits = fn(params, x, sizes)
+        with spans.span(spans.CLOUD_ASSEMBLE):
+            if buckets is None:
+                x = jnp.concatenate([m.x for m in members], axis=0)
+                sizes = jnp.concatenate([m.sizes for m in members], axis=0)
+            else:
+                edge = key[2]
+                # pad once per distinct token count, not once per member: the
+                # eager pad/concat dispatches then scale with the handful of
+                # distinct counts in flight instead of the fleet size
+                by_count: dict[int, list[ExecPlan]] = {}
+                for m in members:
+                    by_count.setdefault(m.x.shape[1], []).append(m)
+                chunks, members = [], []
+                for t in sorted(by_count):
+                    ms = by_count[t]
+                    cx = ms[0].x if len(ms) == 1 else \
+                        jnp.concatenate([m.x for m in ms], axis=0)
+                    cs = ms[0].sizes if len(ms) == 1 else \
+                        jnp.concatenate([m.sizes for m in ms], axis=0)
+                    chunks.append(_pad_tokens(cx, cs, edge))
+                    members.extend(ms)
+                x = chunks[0][0] if len(chunks) == 1 else \
+                    jnp.concatenate([c[0] for c in chunks], axis=0)
+                sizes = chunks[0][1] if len(chunks) == 1 else \
+                    jnp.concatenate([c[1] for c in chunks], axis=0)
+        with spans.span(spans.CLOUD_CALL, batch=x.shape[0]):
+            if buckets is None:
+                fn = cache.cloud_fn(cfg, key[0], key[1], x)
+            else:
+                fn = cache.cloud_padded_fn(cfg, key[0], key[1], x)
+            logits = fn(params, x, sizes)
         off = 0
         for m in members:
             b = m.x.shape[0]
@@ -542,16 +580,20 @@ class JanusEngine:
         Returns the pending ExecPlan and the measured payload size (None = no
         transfer)."""
         n_exec = self.model_cfg.n_layers
-        sched_exec, split_exec = self._exec_geometry(dec)
-        s = n_exec if split_exec == n_exec + 1 else split_exec
-        dev_fn = self.plan_cache.device_fn(self.model_cfg, sched_exec, s, images)
-        x, sizes = dev_fn(self.params, images)
+        with spans.span(spans.DEVICE_PARTITION, split=dec.split):
+            sched_exec, split_exec = self._exec_geometry(dec)
+            s = n_exec if split_exec == n_exec + 1 else split_exec
+            dev_fn = self.plan_cache.device_fn(self.model_cfg, sched_exec, s, images)
+            x, sizes = dev_fn(self.params, images)
         payload_bytes = None
         if split_exec not in (0, n_exec + 1):
-            payload = compression.activation_payload(
-                x, quantize=self.cfg.quantize_payload)
-            x = jnp.asarray(compression.decode_activation(payload),
-                            dtype=self.model_cfg.dtype)
+            # encode waits for the device partition: it reads x on the host
+            with spans.span(spans.CODEC_ENCODE, bytes=x.nbytes):
+                payload = compression.activation_payload(
+                    x, quantize=self.cfg.quantize_payload)
+            with spans.span(spans.CODEC_DECODE, bytes=payload.nbytes):
+                x = jnp.asarray(compression.decode_activation(payload),
+                                dtype=self.model_cfg.dtype)
             payload_bytes = payload.nbytes
         return ExecPlan(sched_exec, split_exec, x=x, sizes=sizes), payload_bytes
 
@@ -566,33 +608,37 @@ class JanusEngine:
     def plan_frame(self, frame_idx: int, trace: NetworkTrace, policy: str,
                    estimator: HarmonicMeanEstimator,
                    images: jax.Array | None = None,
-                   defer_cloud: bool = False) -> FrameStep:
+                   defer_cloud: bool = False, stream: int = 0) -> FrameStep:
         """``decide -> account`` for one frame. Pure with respect to engine
         state: the caller owns the estimator and must ``observe`` the returned
         ``bandwidth_bps`` after the frame (the fleet keeps one estimator per
         stream). With ``defer_cloud=True`` an executed frame's cloud partition
-        is left pending in ``step.exec_plan`` for batched dispatch."""
-        b_est = estimator.estimate()
-        dec = self._decide(policy, b_est, trace.rtt_s)
-        counts = self._counts_for(dec.schedule)
-        b_true = trace.at(frame_idx)
+        is left pending in ``step.exec_plan`` for batched dispatch.
+        ``stream`` only labels the frame's spans."""
+        with spans.span(spans.PLAN_FRAME, stream=stream, frame=frame_idx):
+            b_est = estimator.estimate()
+            with spans.span(spans.DECIDE):
+                dec = self._decide(policy, b_est, trace.rtt_s)
+            counts = self._counts_for(dec.schedule)
+            b_true = trace.at(frame_idx)
 
-        payload_bytes = self._payload_bytes(counts, dec.split)
-        exec_plan = None
-        if self.cfg.execute and self.params is not None and images is not None:
-            exec_plan, measured = self.execute_device(dec, images)
-            if measured is not None:
-                payload_bytes = measured
-            n_exec = self.model_cfg.n_layers
-            if not defer_cloud or exec_plan.split == n_exec + 1:
-                # device-only frames never enter the shared cloud tier, so
-                # their (head-only) cloud program always completes inline
-                self.finish_execution(exec_plan)
+            payload_bytes = self._payload_bytes(counts, dec.split)
+            exec_plan = None
+            if self.cfg.execute and self.params is not None and images is not None:
+                exec_plan, measured = self.execute_device(dec, images)
+                exec_plan.frame_id = (stream, frame_idx)
+                if measured is not None:
+                    payload_bytes = measured
+                n_exec = self.model_cfg.n_layers
+                if not defer_cloud or exec_plan.split == n_exec + 1:
+                    # device-only frames never enter the shared cloud tier, so
+                    # their (head-only) cloud program always completes inline
+                    self.finish_execution(exec_plan)
 
-        bd = self.account_breakdown(counts, dec.split, payload_bytes, b_true,
-                                    trace.rtt_s)
-        acc = self.acc.accuracy(self.profile.x0, dec.schedule) \
-            * self.cfg.accuracy_scale
+            bd = self.account_breakdown(counts, dec.split, payload_bytes, b_true,
+                                        trace.rtt_s)
+            acc = self.acc.accuracy(self.profile.x0, dec.schedule) \
+                * self.cfg.accuracy_scale
         return FrameStep(decision=dec, breakdown=bd, payload_bytes=payload_bytes,
                          bandwidth_bps=b_true, accuracy=acc, exec_plan=exec_plan)
 

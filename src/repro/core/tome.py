@@ -87,11 +87,15 @@ def merge_tokens(x: jax.Array, sizes: jax.Array, idx: MergeIndices):
 
 def tome_merge(x: jax.Array, metric: jax.Array, sizes: jax.Array, r: int, *,
                protect_first: bool = True, scores_fn=None):
-    """Full ToMe step: match on ``metric``, merge ``x``. Returns (x', sizes')."""
+    """Full ToMe step: match on ``metric``, merge ``x``. Returns (x', sizes').
+    Its ops carry the ``tome_merge`` name scope, so that a device trace can
+    sum the merge's time."""
     if r <= 0:
         return x, sizes
-    idx = bipartite_soft_matching(metric, r, protect_first=protect_first, scores_fn=scores_fn)
-    return merge_tokens(x, sizes, idx)
+    with jax.named_scope("tome_merge"):
+        idx = bipartite_soft_matching(metric, r, protect_first=protect_first,
+                                      scores_fn=scores_fn)
+        return merge_tokens(x, sizes, idx)
 
 
 def tome_merge_padded(x: jax.Array, metric: jax.Array, sizes: jax.Array,
@@ -116,34 +120,36 @@ def tome_merge_padded(x: jax.Array, metric: jax.Array, sizes: jax.Array,
     The caller is responsible for keeping pads out of *attention* (token
     sizes of 0 make the proportional-attention bias ``log(0) = -inf``, which
     zeroes their softmax weight exactly). Requires the pure-jnp scoring path:
-    the Pallas ``scores_fn`` kernel has no pad-column masking.
+    the Pallas ``scores_fn`` kernel has no pad-column masking. Its ops
+    carry the ``tome_merge`` name scope, as ``tome_merge``'s do.
     """
     if r <= 0:
         return x, sizes
-    b, n, d = metric.shape
-    na = (n + 1) // 2
-    if not 0 < r < na:
-        raise ValueError(f"r={r} must be in (0, {na})")
-    m = metric.astype(jnp.float32)
-    m = m / (jnp.linalg.norm(m, axis=-1, keepdims=True) + 1e-6)
-    a, bset = m[:, ::2], m[:, 1::2]
-    pad_a = sizes[:, ::2] <= 0.0     # [B, Na]
-    pad_b = sizes[:, 1::2] <= 0.0    # [B, Nb]
-    scores = jnp.einsum("bnd,bmd->bnm", a, bset)
-    scores = jnp.where(pad_b[:, None, :], -jnp.inf, scores)
-    if protect_first:
-        scores = scores.at[:, 0, :].set(-jnp.inf)
-    node_max = jnp.where(pad_a, -jnp.inf, scores.max(axis=-1))
-    node_idx = scores.argmax(axis=-1)
-    order = jnp.argsort(-node_max, axis=-1, stable=True)
-    src_idx = order[:, :r]
-    unm_idx = jnp.sort(order[:, r:], axis=-1)
-    dst_idx = jnp.take_along_axis(node_idx, src_idx, axis=-1)
-    x, sizes = merge_tokens(x, sizes, MergeIndices(src_idx, unm_idx, dst_idx))
-    # pads land mid-sequence (between the unmerged A-set and the B-set);
-    # stably re-sort them to the tail so real-token order — and therefore the
-    # next layer's A/B split — is exactly the unpadded run's
-    tail = jnp.argsort((sizes <= 0.0).astype(jnp.int32), axis=-1, stable=True)
-    x = jnp.take_along_axis(x, tail[:, :, None], axis=1)
-    sizes = jnp.take_along_axis(sizes, tail, axis=1)
-    return x, sizes
+    with jax.named_scope("tome_merge"):
+        b, n, d = metric.shape
+        na = (n + 1) // 2
+        if not 0 < r < na:
+            raise ValueError(f"r={r} must be in (0, {na})")
+        m = metric.astype(jnp.float32)
+        m = m / (jnp.linalg.norm(m, axis=-1, keepdims=True) + 1e-6)
+        a, bset = m[:, ::2], m[:, 1::2]
+        pad_a = sizes[:, ::2] <= 0.0     # [B, Na]
+        pad_b = sizes[:, 1::2] <= 0.0    # [B, Nb]
+        scores = jnp.einsum("bnd,bmd->bnm", a, bset)
+        scores = jnp.where(pad_b[:, None, :], -jnp.inf, scores)
+        if protect_first:
+            scores = scores.at[:, 0, :].set(-jnp.inf)
+        node_max = jnp.where(pad_a, -jnp.inf, scores.max(axis=-1))
+        node_idx = scores.argmax(axis=-1)
+        order = jnp.argsort(-node_max, axis=-1, stable=True)
+        src_idx = order[:, :r]
+        unm_idx = jnp.sort(order[:, r:], axis=-1)
+        dst_idx = jnp.take_along_axis(node_idx, src_idx, axis=-1)
+        x, sizes = merge_tokens(x, sizes, MergeIndices(src_idx, unm_idx, dst_idx))
+        # pads land mid-sequence (between the unmerged A-set and the B-set);
+        # stably re-sort them to the tail so real-token order — and therefore the
+        # next layer's A/B split — is exactly the unpadded run's
+        tail = jnp.argsort((sizes <= 0.0).astype(jnp.int32), axis=-1, stable=True)
+        x = jnp.take_along_axis(x, tail[:, :, None], axis=1)
+        sizes = jnp.take_along_axis(sizes, tail, axis=1)
+        return x, sizes

@@ -814,7 +814,7 @@ class FleetRuntime:
         def start_frame(si: int, fi: int, t0: float) -> None:
             eng, spec = self.engines[si], streams[si]
             step = eng.plan_frame(fi, spec.trace, spec.policy, estimators[si],
-                                  images=images, defer_cloud=True)
+                                  images=images, defer_cloud=True, stream=si)
             estimators[si].observe(step.bandwidth_bps)
             bd = step.breakdown
             # one device per client: overlapping open-loop frames serialize

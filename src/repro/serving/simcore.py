@@ -715,7 +715,7 @@ def simulate(rt, images=None, record: list | None = None, telemetry=None):
             eng, spec = rt.engines[si], streams[si]
             step = eng.plan_frame(fi, spec.trace, spec.policy,
                                   estimators[si], images=images,
-                                  defer_cloud=True)
+                                  defer_cloud=True, stream=si)
             estimators[si].observe(step.bandwidth_bps)
             bd = step.breakdown
             dev_s, comm_s, cloud_s = bd.device_s, bd.comm_s, bd.cloud_s
