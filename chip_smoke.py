@@ -44,8 +44,9 @@ from compile_counter import CompileCounter  # noqa: E402
 
 # 4 streams x 3 frames on fixed 4G/static traces. On the paper's timing
 # profile the planner picks alpha=0.27 at split 0 for most frames (ToMe
-# merges inside the bucketed cloud partition) and device-only for some
-# (all 24 merge layers in the device partition, head-only cloud program).
+# merges inside the bucketed cloud program, which embeds the raw frames)
+# and device-only for some (all 24 merge layers and the head in the
+# device program).
 ONE_CHIP_ARGV = ["--streams", "4", "--frames", "3", "--network", "4g",
                  "--mobility", "static", "--sla-ms", "150", "--seed", "0",
                  "--execute", "--bucket-edges", "2", "--telemetry-sample", "0"]

@@ -27,7 +27,12 @@ Partition programs are ``jax.jit``-compiled once per (schedule, split, batch)
 geometry and cached in a ``CompiledPlanCache`` — repeat frames with the same
 decision reuse the compiled executable instead of retracing, and the fleet
 runtime batches same-geometry cloud partitions from a micro-batch into one
-stacked forward (``run_cloud_batch``).
+stacked forward (``run_cloud_batch``). At split 0 and split n+1 the partition
+that runs no transformer block is not a program of its own: a cloud-only
+frame's embed runs inside the batched cloud program, a device-only frame's
+head inside its device program, where ``account_breakdown`` bills each. At
+split n the head-only cloud partition stays a program, since the activation
+crosses the wire there.
 
 Baselines (§V-B): Device-Only / Cloud-Only / Mixed (NeuroSurgeon degenerates to
 Mixed for ViTs), each with ToMe's maximum fixed pruning level.
@@ -130,15 +135,20 @@ class FrameResult:
 class ExecPlan:
     """Pending real-math execution state for one frame (execute=True).
 
-    The device partition runs at plan time; ``x``/``sizes`` hold the
-    post-wire activation entering the cloud partition. ``logits`` is filled
-    either inline (single-stream / device-only) or by ``run_cloud_batch``
-    when the fleet dispatches the frame's micro-batch."""
+    At split 0 and split n+1 the partition that runs no transformer block
+    is folded into its neighbour's program. At any other split the device partition runs at
+    plan time and ``x``/``sizes`` hold the post-wire activation entering the
+    cloud partition. At split 0 nothing runs at plan time: ``images`` holds
+    the raw frame, which the cloud program embeds. A device-only frame's
+    device program ends with the head, so its ``logits`` are set at plan
+    time. Otherwise ``logits`` is filled inline (single stream) or by
+    ``run_cloud_batch`` when the fleet dispatches the frame's micro-batch."""
     schedule: tuple[int, ...]   # exec-geometry merge schedule
     split: int                  # exec-geometry split (0..n_exec+1)
     x: Any = None
     sizes: Any = None
     logits: Any = None
+    images: Any = None          # split 0 only: the frame the cloud embeds
     frame_id: tuple[int, int] | None = None   # (stream, frame), for spans
 
 
@@ -252,6 +262,14 @@ def split_inference(params: dict, cfg: vit_lib.ViTConfig, images: jax.Array,
     return logits, payload
 
 
+def _as_stored(x: jax.Array) -> jax.Array:
+    """``x`` as a program boundary stores it, in its own dtype. Where a
+    folded partition meets its neighbour inside one program, XLA could
+    otherwise carry the value wider than the two programs did, and in bf16
+    those ulps can flip a near-tied ToMe merge."""
+    return jax.lax.optimization_barrier(x)
+
+
 class CompiledPlanCache:
     """``jax.jit`` executables for device/cloud partition programs, keyed by
     (partition, model config, schedule, split, input geometry).
@@ -265,9 +283,13 @@ class CompiledPlanCache:
     execute bench can bound *cloud* retraces by the bucket-table cell count.
     ``build_s`` sums the wall seconds of each new program's first call
     (trace, lower, compile or load from the persistent cache, dispatch),
-    each under a ``janus/program_build`` span. The jitted functions are
-    named ``janus_device``, ``janus_cloud`` and ``janus_cloud_padded``, so
-    XLA names their programs ``jit_janus_*``.
+    each under a ``janus/program_build`` span. ``folded`` counts the
+    frames whose empty partition ran inside its neighbour's program:
+    ``embed`` for split-0 frames embedded by ``cloud_embed_fn``, ``head``
+    for device-only frames whose device program ends with the head. The
+    jitted functions are named ``janus_device``, ``janus_cloud``,
+    ``janus_cloud_padded`` and ``janus_cloud_embed``, so XLA names their
+    programs ``jit_janus_*``.
 
     ``rules`` (optional ``sharding.Rules``) makes every compiled partition
     mesh-aware: the partition programs trace under ``use_rules``, so the
@@ -286,6 +308,7 @@ class CompiledPlanCache:
         self.traces = 0
         self.traces_by_kind: dict[str, int] = {}
         self.build_s = 0.0
+        self.folded = {"embed": 0, "head": 0}
 
     def _bump(self, kind: str) -> None:
         self.traces += 1
@@ -319,13 +342,20 @@ class CompiledPlanCache:
 
     def device_fn(self, cfg: vit_lib.ViTConfig, schedule: tuple[int, ...],
                   split: int, images) -> Callable:
+        """Device partition: embed + layers [0, split), returning (x, sizes);
+        at ``split == n + 1`` (device-only) all layers and the head,
+        returning logits."""
         key = ("device", cfg, schedule, split, self._shape_key(images))
+        n = cfg.n_layers
 
         def build():
             def janus_device(params, images):
                 self._bump("device")
                 with rules_lib.use_rules(self.rules):
-                    return device_forward(params, cfg, images, schedule, split)
+                    if split <= n:
+                        return device_forward(params, cfg, images, schedule, split)
+                    x, _ = device_forward(params, cfg, images, schedule, n)
+                    return vit_lib.head_apply(params, cfg, _as_stored(x))
             return jax.jit(janus_device)
 
         return self._get(key, build)
@@ -360,6 +390,26 @@ class CompiledPlanCache:
                         params, cfg, x, sizes, schedule, split, cfg.n_layers)
                     return vit_lib.head_apply(params, cfg, x2)
             return jax.jit(janus_cloud_padded)
+
+        return self._get(key, build)
+
+    def cloud_embed_fn(self, cfg: vit_lib.ViTConfig, schedule: tuple[int, ...],
+                       images) -> Callable:
+        """Cloud-only (split 0) partition: embed + every layer + head on the
+        raw frames. Every frame enters with all ``num_tokens`` tokens, so
+        nothing is padded, bucketed fleet or not."""
+        key = ("cloud_embed", cfg, schedule, self._shape_key(images))
+
+        def build():
+            def janus_cloud_embed(params, images):
+                self._bump("cloud_embed")
+                with rules_lib.use_rules(self.rules):
+                    x = _as_stored(vit_lib.embed_tokens(params, cfg, images))
+                    x, _ = vit_lib.run_blocks(params, cfg, x,
+                                              vit_lib.initial_sizes(x),
+                                              schedule, 0, cfg.n_layers)
+                    return vit_lib.head_apply(params, cfg, x)
+            return jax.jit(janus_cloud_embed)
 
         return self._get(key, build)
 
@@ -403,28 +453,42 @@ def _frame_ids(plans: Sequence[ExecPlan]) -> str:
                     if p.frame_id is not None)
 
 
+def _cat(arrays: Sequence[jax.Array]) -> jax.Array:
+    """Stack along the batch axis; one array is passed through as it is."""
+    return arrays[0] if len(arrays) == 1 else jnp.concatenate(arrays, axis=0)
+
+
 def _run_cloud_batch(cache: CompiledPlanCache, cfg: vit_lib.ViTConfig,
                      params: dict, plans: Sequence[ExecPlan],
                      buckets: BucketTable | None) -> None:
-    n = cfg.n_layers
     groups: dict[tuple, list[ExecPlan]] = {}
     with spans.span(spans.CLOUD_ASSEMBLE):
         for plan in plans:
-            s = n if plan.split == n + 1 else plan.split
-            if buckets is None:
-                key = (plan.schedule, s, tuple(plan.x.shape[1:]), str(plan.x.dtype))
+            s = plan.split
+            if plan.x is None:
+                # split 0: the raw frames, embedded by the cloud program; an
+                # image's shape is not a token count, so no bucket edge
+                im = plan.images
+                key = ("cloud_embed", plan.schedule, tuple(im.shape[1:]),
+                       str(im.dtype))
+            elif buckets is None:
+                key = ("cloud", plan.schedule, s, tuple(plan.x.shape[1:]),
+                       str(plan.x.dtype))
             else:
                 edge = buckets.edge_for(s, plan.x.shape[1])
-                key = (plan.schedule[s:], s, edge, plan.x.shape[2],
-                       str(plan.x.dtype))
+                key = ("cloud_padded", plan.schedule[s:], s, edge,
+                       plan.x.shape[2], str(plan.x.dtype))
             groups.setdefault(key, []).append(plan)
     for key, members in groups.items():
+        kind = key[0]
         with spans.span(spans.CLOUD_ASSEMBLE):
-            if buckets is None:
-                x = jnp.concatenate([m.x for m in members], axis=0)
-                sizes = jnp.concatenate([m.sizes for m in members], axis=0)
+            if kind == "cloud_embed":
+                args = (_cat([m.images for m in members]),)
+            elif kind == "cloud":
+                args = (_cat([m.x for m in members]),
+                        _cat([m.sizes for m in members]))
             else:
-                edge = key[2]
+                edge = key[3]
                 # pad once per distinct token count, not once per member: the
                 # eager pad/concat dispatches then scale with the handful of
                 # distinct counts in flight instead of the fleet size
@@ -434,25 +498,23 @@ def _run_cloud_batch(cache: CompiledPlanCache, cfg: vit_lib.ViTConfig,
                 chunks, members = [], []
                 for t in sorted(by_count):
                     ms = by_count[t]
-                    cx = ms[0].x if len(ms) == 1 else \
-                        jnp.concatenate([m.x for m in ms], axis=0)
-                    cs = ms[0].sizes if len(ms) == 1 else \
-                        jnp.concatenate([m.sizes for m in ms], axis=0)
-                    chunks.append(_pad_tokens(cx, cs, edge))
+                    chunks.append(_pad_tokens(_cat([m.x for m in ms]),
+                                              _cat([m.sizes for m in ms]), edge))
                     members.extend(ms)
-                x = chunks[0][0] if len(chunks) == 1 else \
-                    jnp.concatenate([c[0] for c in chunks], axis=0)
-                sizes = chunks[0][1] if len(chunks) == 1 else \
-                    jnp.concatenate([c[1] for c in chunks], axis=0)
-        with spans.span(spans.CLOUD_CALL, batch=x.shape[0]):
-            if buckets is None:
-                fn = cache.cloud_fn(cfg, key[0], key[1], x)
+                args = (_cat([c[0] for c in chunks]),
+                        _cat([c[1] for c in chunks]))
+        with spans.span(spans.CLOUD_CALL, batch=args[0].shape[0]):
+            if kind == "cloud_embed":
+                fn = cache.cloud_embed_fn(cfg, key[1], args[0])
+                cache.folded["embed"] += len(members)
+            elif kind == "cloud":
+                fn = cache.cloud_fn(cfg, key[1], key[2], args[0])
             else:
-                fn = cache.cloud_padded_fn(cfg, key[0], key[1], x)
-            logits = fn(params, x, sizes)
+                fn = cache.cloud_padded_fn(cfg, key[1], key[2], args[0])
+            logits = fn(params, *args)
         off = 0
         for m in members:
-            b = m.x.shape[0]
+            b = (m.images if m.x is None else m.x).shape[0]
             m.logits = logits[off:off + b]
             off += b
 
@@ -578,28 +640,37 @@ class JanusEngine:
     def execute_device(self, dec: Decision, images: jax.Array) -> tuple[ExecPlan, float | None]:
         """Run the device partition (compiled) and encode the wire payload.
         Returns the pending ExecPlan and the measured payload size (None = no
-        transfer)."""
+        transfer).
+
+        At split 0 and split n+1 the partition with no transformer block is
+        not a program of its own: at split 0 nothing is dispatched and the plan carries the images for
+        the cloud program to embed; a device-only frame's device program
+        ends with the head and the plan comes back with its logits."""
         n_exec = self.model_cfg.n_layers
+        sched_exec, split_exec = self._exec_geometry(dec)
+        if split_exec == 0:
+            return ExecPlan(sched_exec, 0, images=images), None
         with spans.span(spans.DEVICE_PARTITION, split=dec.split):
-            sched_exec, split_exec = self._exec_geometry(dec)
-            s = n_exec if split_exec == n_exec + 1 else split_exec
-            dev_fn = self.plan_cache.device_fn(self.model_cfg, sched_exec, s, images)
-            x, sizes = dev_fn(self.params, images)
-        payload_bytes = None
-        if split_exec not in (0, n_exec + 1):
-            # encode waits for the device partition: it reads x on the host
-            with spans.span(spans.CODEC_ENCODE, bytes=x.nbytes):
-                payload = compression.activation_payload(
-                    x, quantize=self.cfg.quantize_payload)
-            with spans.span(spans.CODEC_DECODE, bytes=payload.nbytes):
-                x = jnp.asarray(compression.decode_activation(payload),
-                                dtype=self.model_cfg.dtype)
-            payload_bytes = payload.nbytes
-        return ExecPlan(sched_exec, split_exec, x=x, sizes=sizes), payload_bytes
+            dev_fn = self.plan_cache.device_fn(self.model_cfg, sched_exec,
+                                               split_exec, images)
+            out = dev_fn(self.params, images)
+        if split_exec == n_exec + 1:
+            self.plan_cache.folded["head"] += 1
+            return ExecPlan(sched_exec, split_exec, logits=out), None
+        x, sizes = out
+        # encode waits for the device partition: it reads x on the host
+        with spans.span(spans.CODEC_ENCODE, bytes=x.nbytes):
+            payload = compression.activation_payload(
+                x, quantize=self.cfg.quantize_payload)
+        with spans.span(spans.CODEC_DECODE, bytes=payload.nbytes):
+            x = jnp.asarray(compression.decode_activation(payload),
+                            dtype=self.model_cfg.dtype)
+        return ExecPlan(sched_exec, split_exec, x=x, sizes=sizes), payload.nbytes
 
     def finish_execution(self, plan: ExecPlan) -> None:
         """Run a pending cloud partition inline (single-stream path; the fleet
-        batches same-geometry plans via ``run_cloud_batch`` instead)."""
+        batches same-geometry plans via ``run_cloud_batch`` instead). A
+        device-only plan has its logits already and dispatches nothing."""
         if plan.logits is not None:
             return
         run_cloud_batch(self.plan_cache, self.model_cfg, self.params, [plan])
@@ -629,10 +700,7 @@ class JanusEngine:
                 exec_plan.frame_id = (stream, frame_idx)
                 if measured is not None:
                     payload_bytes = measured
-                n_exec = self.model_cfg.n_layers
-                if not defer_cloud or exec_plan.split == n_exec + 1:
-                    # device-only frames never enter the shared cloud tier, so
-                    # their (head-only) cloud program always completes inline
+                if not defer_cloud:
                     self.finish_execution(exec_plan)
 
             bd = self.account_breakdown(counts, dec.split, payload_bytes, b_true,

@@ -288,9 +288,10 @@ def run_fleet(args, profile, eng_cfg, model_cfg=None, params=None, images=None):
         buckets = f" bucket_cells={rt.buckets.n_cells}" if rt.buckets else ""
         mesh = f" mesh={tuple(rt.mesh_rules.mesh.shape.items())}" \
             if rt.mesh_rules is not None else ""
+        folded = " ".join(f"{k}={v}" for k, v in pc.folded.items())
         print(f"[fleet execute] plan_cache hits={pc.hits} misses={pc.misses} "
-              f"traces={pc.traces} ({by_kind}) build_s={pc.build_s:.3f}"
-              f"{buckets}{mesh}")
+              f"traces={pc.traces} ({by_kind}) folded=({folded}) "
+              f"build_s={pc.build_s:.3f}{buckets}{mesh}")
     if spec.autoscale is not None:
         print(f"[fleet autoscale] capacity peak={fs.peak_capacity} "
               f"final={fs.final_capacity} "

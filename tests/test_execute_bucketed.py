@@ -13,6 +13,10 @@ Covers the continuous-batching fast path end to end:
   * fleet integration: bucketing changes neither the simulated timing plane
     nor the logits, and cuts compiled cloud geometries
   * mesh-sharded execution (1-device dp mesh) reproduces the unsharded path
+  * at split 0 and split n + 1 the partition with no transformer block is
+    folded into its neighbour's program, rounding as the two programs did,
+    and interior splits dispatch what they always did (a fleet's folded
+    frames: ``tests/test_spans.py``)
 """
 import jax
 import jax.numpy as jnp
@@ -22,6 +26,7 @@ from conftest import small_model_profile as _profile
 
 from repro.core import bandwidth, engine, pruning, tome
 from repro.core.bucketing import BucketingConfig, BucketTable, bucket_edges
+from repro.core.scheduler import Decision
 from repro.models import param as param_lib
 from repro.models import vit as vit_lib
 from repro.serving import fleet
@@ -331,3 +336,56 @@ def test_fleet_mesh_rules_single_device_parity():
         np.testing.assert_allclose(np.asarray(f0.logits),
                                    np.asarray(f1.logits),
                                    atol=1e-5, rtol=1e-5)
+
+
+# ------------------------------------- partitions with no transformer block
+
+def test_interior_split_dispatches_what_it_did():
+    """At an interior split the device partition, the codec and the padded
+    cloud program run as before, and nothing is folded."""
+    cfg = _cfg50()
+    params = _params(cfg)
+    img = jax.random.normal(jax.random.key(100), (1, cfg.img_res, cfg.img_res, 3))
+    prof = _profile()
+    eng = engine.JanusEngine(prof, engine.EngineConfig(sla_s=0.5, execute=True),
+                             model_cfg=cfg, params=params)
+    # the profile's split that the engine maps onto SPLIT of the stand-in
+    split = SPLIT * prof.n_layers // cfg.n_layers
+    plan, payload = eng.execute_device(Decision(0.5, split, 0.0, True, ()), img)
+    assert plan.split == SPLIT
+    assert plan.x is not None and plan.images is None and payload > 0
+    assert plan.logits is None
+    table = BucketTable.build(cfg, ALPHAS, config=BucketingConfig(n_edges=2))
+    engine.run_cloud_batch(eng.plan_cache, cfg, params, [plan], buckets=table)
+    pc = eng.plan_cache
+    assert pc.traces_by_kind == {"device": 1, "cloud_padded": 1}
+    assert pc.hits + pc.misses == 2
+    assert pc.folded == {"embed": 0, "head": 0}
+    ref, _ = engine.split_inference(params, cfg, img, plan.schedule, SPLIT,
+                                    quantize=True)
+    np.testing.assert_allclose(np.asarray(plan.logits), np.asarray(ref),
+                               atol=PAD_ATOL, rtol=PAD_ATOL)
+
+
+@pytest.mark.parametrize("split", ["cloud_only", "device_only"])
+def test_folded_program_rounds_as_the_two_programs(split):
+    """In bf16 a folded program equals the two programs it replaces bit for
+    bit: the value at the seam is stored in bf16 as a program boundary
+    stores it, so no near-tied merge can flip."""
+    cfg = vit_lib.ViTConfig(img_res=56, patch=8, n_layers=6, d_model=32,
+                            n_heads=2, d_ff=64, n_classes=8,
+                            dtype=jnp.bfloat16)
+    params = param_lib.init_params(vit_lib.specs(cfg), jax.random.key(0),
+                                   dtype=cfg.dtype)
+    imgs = jax.random.normal(jax.random.key(5), (3, cfg.img_res, cfg.img_res, 3))
+    sched = tuple(pruning.make_schedule("exponential", 0.7, cfg.n_layers,
+                                        cfg.num_tokens))
+    cache = engine.CompiledPlanCache()
+    s = 0 if split == "cloud_only" else cfg.n_layers + 1
+    if s == 0:
+        got = cache.cloud_embed_fn(cfg, sched, imgs)(params, imgs)
+    else:
+        got = cache.device_fn(cfg, sched, s, imgs)(params, imgs)
+    ref, _ = engine.split_inference(params, cfg, imgs, sched, s)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(ref, np.float32))

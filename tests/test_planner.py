@@ -201,17 +201,21 @@ def test_compiled_plan_cache_no_retrace_on_repeat_geometry():
 
     step0 = eng.plan_frame(0, trace, "janus", est, images=images)
     est.observe(step0.bandwidth_bps)
+    # cloud-only at this bandwidth: no device program, one cloud program
+    # that embeds the frame itself
+    assert step0.decision.split == 0, "geometry precondition drifted"
     traces_after_first = eng.plan_cache.traces
-    assert traces_after_first == 2, "device + cloud partition traced once each"
-    assert eng.plan_cache.misses == 2 and eng.plan_cache.hits == 0
+    assert eng.plan_cache.traces_by_kind == {"cloud_embed": 1}, \
+        "the cloud-only partition traced once"
+    assert eng.plan_cache.misses == 1 and eng.plan_cache.hits == 0
 
     for i in (1, 2, 3):
         step = eng.plan_frame(i, trace, "janus", est, images=images)
         est.observe(step.bandwidth_bps)
         assert step.decision.split == step0.decision.split
     assert eng.plan_cache.traces == traces_after_first, "retraced on repeat"
-    assert eng.plan_cache.misses == 2
-    assert eng.plan_cache.hits == 6
+    assert eng.plan_cache.misses == 1
+    assert eng.plan_cache.hits == 3
     assert step.exec_plan.logits is not None
 
 

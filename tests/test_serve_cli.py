@@ -18,7 +18,9 @@ def test_execute_smoke_model_runs_the_fleet_path(capsys):
                 "--bucket-edges", "2", "--telemetry-sample", "0"])
     out = capsys.readouterr().out
     assert "[fleet aggregate] frames=4" in out
-    assert "[fleet execute]" in out and "cloud_padded=1" in out
+    # on WiFi every frame is cloud-only: one program embeds all four
+    assert "[fleet execute]" in out and "(cloud_embed=1)" in out
+    assert "folded=(embed=4 head=0)" in out
 
 
 def test_smoke_model_requires_execute():

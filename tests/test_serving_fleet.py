@@ -294,11 +294,14 @@ def test_execute_at_profiled_geometry_runs_the_decision():
 
 def test_fleet_shares_one_plan_cache_across_streams():
     """Same-geometry streams compile each partition program once fleet-wide:
-    the shared cache traces exactly (device + cloud) once."""
+    the shared cache traces the cloud-only program (which embeds the
+    frames itself) exactly once."""
     rt, fs, _ = _exec_fleet(4, 2, max_batch=4)
     assert all(e.plan_cache is rt.plan_cache for e in rt.engines)
-    assert rt.plan_cache.traces == 2, \
-        f"expected 1 device + 1 cloud trace, got {rt.plan_cache.traces}"
+    assert {f.split for f in fs.all_frames} == {0}, \
+        "geometry precondition drifted"
+    assert rt.plan_cache.traces_by_kind == {"cloud_embed": 1}, \
+        f"expected 1 cloud_embed trace, got {rt.plan_cache.traces_by_kind}"
     assert rt.plan_cache.hits > 0
 
 

@@ -6,7 +6,9 @@ with ``jax.profiler.trace`` around it (cold: every program is built then)
 and once without. The trace's host plane must hold one span per frame and
 per micro-batch with the args the docs promise, the programs must carry
 stable ``jit_janus_*`` names and the ToMe merge its ``tome_merge`` scope,
-and the profiler must change nothing that the run returns.
+and the profiler must change nothing that the run returns. The same fleet
+shows the folded partitions: at split 0 and split n + 1 the partition
+with no transformer block runs inside its neighbour's program.
 """
 import dataclasses
 import pathlib
@@ -18,12 +20,13 @@ import numpy as np
 import pytest
 
 from repro.core import engine, pruning
+from repro.core.scheduler import Decision
 from repro.launch import serve
 from repro.runtime import spans
 
 # 4G/static on the paper's timing profile: most frames offload at alpha
-# near 0.27 (ToMe merges in padded cloud micro-batches), some run
-# device-only (their head-only cloud program runs inline)
+# near 0.27 (ToMe merges in padded cloud micro-batches that embed the raw
+# frames), some run device-only (their device program ends with the head)
 ARGV = ["--streams", "4", "--frames", "3", "--network", "4g",
         "--mobility", "static", "--sla-ms", "150", "--seed", "0",
         "--execute", "--smoke-model", "--bucket-edges", "2",
@@ -53,19 +56,20 @@ def _build():
     eng_cfg = dataclasses.replace(eng_cfg, include_scheduler_overhead=False)
     rt = serve.build_fleet(args, serve.spec_from_args(args), profile,
                            eng_cfg, cfg, params)
-    return rt, cfg, images
+    return rt, cfg, params, images
 
 
 @pytest.fixture(scope="module")
 def served(tmp_path_factory):
-    rt, cfg, images = _build()
+    rt, cfg, params, images = _build()
     d = tmp_path_factory.mktemp("trace")
     with jax.profiler.trace(str(d)):
         fs_on = rt.run(images=images)
         jax.block_until_ready([f.logits for f in fs_on.all_frames])
+    folded_on = dict(rt.plan_cache.folded)
     fs_off = rt.run(images=images)
-    return dict(rt=rt, cfg=cfg, fs_on=fs_on, fs_off=fs_off,
-                spans=host_spans(d))
+    return dict(rt=rt, cfg=cfg, params=params, images=images, fs_on=fs_on,
+                fs_off=fs_off, folded_on=folded_on, spans=host_spans(d))
 
 
 def _frame_ids(fs) -> list[str]:
@@ -73,11 +77,23 @@ def _frame_ids(fs) -> list[str]:
             for fi in range(len(st.frames))]
 
 
+def _block_frames(fs):
+    """Frames whose device partition runs transformer blocks: a cloud-only
+    frame (split 0) dispatches no device program."""
+    return [f for f in fs.all_frames if f.split != 0]
+
+
 @pytest.mark.parametrize("name", [spans.PLAN_FRAME, spans.DECIDE,
                                   spans.DEVICE_PARTITION])
 def test_one_span_per_frame(served, name):
+    fs = served["fs_on"]
+    frames = fs.all_frames
+    if name == spans.DEVICE_PARTITION:
+        frames = _block_frames(fs)
+        assert len(frames) < len(fs.all_frames), \
+            "the scenario should hold cloud-only frames"
     n = sum(1 for nm, _ in served["spans"] if nm == name)
-    assert n == len(served["fs_on"].all_frames)
+    assert n == len(frames)
 
 
 def test_plan_frame_args_name_every_frame(served):
@@ -89,20 +105,22 @@ def test_plan_frame_args_name_every_frame(served):
 def test_device_partition_carries_the_split(served):
     got = Counter(int(a["split"]) for nm, a in served["spans"]
                   if nm == spans.DEVICE_PARTITION)
-    assert got == Counter(f.split for f in served["fs_on"].all_frames)
+    assert got == Counter(f.split for f in _block_frames(served["fs_on"]))
+    assert 0 not in got
 
 
 def test_one_cloud_batch_span_per_micro_batch(served):
     """Every micro-batch is one ``janus/cloud_batch`` whose ``ids`` name its
-    members; a device-only frame's head-only program is one more, inline."""
+    members; a device-only frame's head runs in its device program, so it
+    has no cloud batch of its own."""
     fs, n = served["fs_on"], served["rt"].engines[0].profile.n_layers
     batches = [a for nm, a in served["spans"] if nm == spans.CLOUD_BATCH]
-    device_only = [f for f in fs.all_frames if f.split == n + 1]
+    device_only = {f"{si}:{fi}" for si, st in enumerate(fs.per_stream)
+                   for fi, f in enumerate(st.frames) if f.split == n + 1}
     assert device_only, "the scenario should hold device-only frames"
-    assert sorted(int(a["frames"]) for a in batches) == \
-        sorted(fs.batch_sizes + [1] * len(device_only))
+    assert sorted(int(a["frames"]) for a in batches) == sorted(fs.batch_sizes)
     ids = sorted(i for a in batches for i in a["ids"].split(","))
-    assert ids == sorted(_frame_ids(fs))
+    assert ids == sorted(set(_frame_ids(fs)) - device_only)
 
 
 def test_program_build_once_per_miss(served):
@@ -133,6 +151,55 @@ def test_profiler_changes_nothing_returned(served):
                                       np.asarray(f_off.logits))
 
 
+# ------------------------------------- partitions with no transformer block
+
+def _by_split(served) -> dict[int, list]:
+    fs, n = served["fs_on"], served["rt"].engines[0].profile.n_layers
+    by_split = {s: [f for f in fs.all_frames if f.split == s]
+                for s in (0, n + 1)}
+    assert by_split[0] and by_split[n + 1], "geometry precondition drifted"
+    assert len(by_split[0]) + len(by_split[n + 1]) == len(fs.all_frames)
+    return by_split
+
+
+def _exec_geometry(rt, frame):
+    dec = Decision(frame.alpha, frame.split, 0.0, True, ())
+    return rt.engines[0]._exec_geometry(dec)
+
+
+def test_folded_partitions_match_split_inference(served):
+    """Cloud-only and device-only frames of a fleet tick give the logits of
+    the unbatched two-program round trip."""
+    rt, cfg = served["rt"], served["cfg"]
+    for f in served["fs_on"].all_frames:
+        sched, split = _exec_geometry(rt, f)
+        ref, _ = engine.split_inference(served["params"], cfg,
+                                        served["images"], sched, split)
+        np.testing.assert_allclose(np.asarray(f.logits), np.asarray(ref),
+                                   atol=2e-6, rtol=2e-6,
+                                   err_msg=f"alpha={f.alpha} split={f.split}")
+
+
+def test_folded_partitions_build_no_program_of_their_own(served):
+    """No device program for a split-0 frame, no head-only cloud program for
+    a device-only one: one device program per device-only schedule and
+    cloud_embed programs for the rest."""
+    rt, n = served["rt"], served["cfg"].n_layers
+    device_only = _by_split(served)[rt.engines[0].profile.n_layers + 1]
+    by_kind = rt.plan_cache.traces_by_kind
+    assert set(by_kind) == {"device", "cloud_embed"}
+    assert by_kind["device"] == len({_exec_geometry(rt, f)
+                                     for f in device_only})
+    assert all(_exec_geometry(rt, f)[1] == n + 1 for f in device_only)
+
+
+def test_folded_counts_every_folded_frame(served):
+    by_split = _by_split(served)
+    n = served["rt"].engines[0].profile.n_layers
+    assert served["folded_on"] == {
+        "embed": len(by_split[0]), "head": len(by_split[n + 1])}
+
+
 # ------------------------------------------------- programs, outside a fleet
 
 @pytest.fixture(scope="module")
@@ -152,6 +219,9 @@ def _program(small, kind):
     if kind == "device":
         args = (params, images)
         get = lambda: cache.device_fn(cfg, sched, cfg.n_layers, images)  # noqa: E731
+    elif kind == "cloud_embed":
+        args = (params, images)
+        get = lambda: cache.cloud_embed_fn(cfg, sched, images)  # noqa: E731
     else:
         x, sizes = engine.device_forward(params, cfg, images, sched, 0)
         args = (params, x, sizes)
@@ -165,13 +235,14 @@ def _program(small, kind):
     return get(), args, cache
 
 
-@pytest.mark.parametrize("kind", ["device", "cloud", "cloud_padded"])
+@pytest.mark.parametrize("kind", ["device", "cloud", "cloud_padded",
+                                  "cloud_embed"])
 def test_program_is_named(small, kind):
     fn, args, _ = _program(small, kind)
     assert f"module @jit_janus_{kind} " in fn.lower(*args).as_text()
 
 
-@pytest.mark.parametrize("kind", ["device", "cloud_padded"])
+@pytest.mark.parametrize("kind", ["device", "cloud_padded", "cloud_embed"])
 def test_merge_ops_carry_the_tome_merge_scope(small, kind):
     """At alpha > 0 the merge's ops carry ``tome_merge`` in their name stack
     (the ``op_name`` metadata a device trace shows); the head does not."""
