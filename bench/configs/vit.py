@@ -20,6 +20,10 @@ reference) or ``"int8"`` / ``"fp8"`` (every linear layer's input quantized
 per token and its weight per output channel, symmetric int8 or float8
 e4m3, products summed in float32: the control a lower-precision path would
 be held against).
+
+Besides the reference it gives the harness the family's geometry:
+``num_tokens`` (the tokens that enter block 0) and ``frame_flops`` (the
+operations one frame requires, counted from its shapes).
 """
 from __future__ import annotations
 
@@ -44,6 +48,40 @@ def merge_schedule(alpha: float, n_layers: int, n_tokens: int) -> tuple[int, ...
         out.append(r)
         x -= r
     return tuple(out)
+
+
+# -- geometry -----------------------------------------------------------------
+
+def num_tokens(c: dict) -> int:
+    """Tokens that enter block 0: the patches and the class token."""
+    return (c["image_size"] // c["patch_size"]) ** 2 + 1
+
+
+def frame_flops(cfg: dict, schedule) -> float:
+    """Operations of one frame of configuration ``cfg`` (the sizes file's
+    keys) whose block ``l`` merges ``schedule[l]`` tokens.
+
+    Matrix products only, two operations per multiply-add: the patch
+    embedding, per block the q/k/v/output projections, the attention scores
+    and their weighted sum over the tokens that enter the block, the ToMe
+    similarity scores of a merging block (its A tokens against its B tokens,
+    on the head-mean keys), the MLP over the tokens left after the merge, and
+    the head on the class token. Padding that a batched program adds is not
+    counted: it is work the frame does not need."""
+    d, f = cfg["hidden_size"], cfg["mlp_dim"]
+    hd = d // cfg["num_heads"]
+    p = cfg["patch_size"]
+    n_patch = (cfg["image_size"] // p) ** 2
+    total = 2.0 * n_patch * (p * p * 3) * d
+    n = n_patch + 1
+    for r in schedule:
+        total += 2.0 * n * d * d * 4          # q, k, v, output projections
+        total += 2.0 * n * n * d * 2          # scores and weighted values
+        if r:
+            total += 2.0 * ((n + 1) // 2) * (n // 2) * hd
+        n -= r
+        total += 2.0 * n * d * f * 2          # MLP
+    return total + 2.0 * d * cfg["num_classes"]
 
 
 # -- layers -------------------------------------------------------------------
@@ -198,8 +236,8 @@ def init_params(sizes_cfg: dict, key, dtype):
     N(0, 1/fan_in), biases, class token and position embedding N(0, 0.02^2),
     LayerNorm scales 1 + N(0, 0.02^2) and shifts N(0, 0.02^2). Every leaf is
     random, so no bias or norm parameter can be left out unseen."""
-    img, p_, n_layers, d, _, f, n_cls = _geometry(sizes_cfg)
-    pdim, n_tok = p_ * p_ * 3, (img // p_) ** 2 + 1
+    _, p_, n_layers, d, _, f, n_cls = _geometry(sizes_cfg)
+    pdim, n_tok = p_ * p_ * 3, num_tokens(sizes_cfg)
     shapes = {
         "patch_embed": {"w": (pdim, d), "b": (d,)},
         "cls": (1, 1, d), "pos": (1, n_tok, d),
@@ -238,9 +276,8 @@ def logits(params: dict, sizes_cfg: dict, images, alpha: float, split: int,
     """Reference logits [B, n_classes] for frames ``images`` [B, H, W, C]
     that were all served at (``alpha``, ``split``), at the highest matmul
     precision; one program per (configuration, precision, batch)."""
-    n_tokens = (sizes_cfg["image_size"] // sizes_cfg["patch_size"]) ** 2 + 1
-    schedule = jnp.asarray(merge_schedule(alpha, sizes_cfg["num_layers"], n_tokens),
-                           jnp.int32)
+    schedule = jnp.asarray(merge_schedule(alpha, sizes_cfg["num_layers"],
+                                          num_tokens(sizes_cfg)), jnp.int32)
     key = (_geometry(sizes_cfg), precision)
     fn = _PROGRAMS.get(key)
     if fn is None:

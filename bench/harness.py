@@ -6,10 +6,19 @@ per-layer metric is a file of its own, found by the name that
 ``BENCHMARK.json`` gives it:
 
 * ``bench/configs/<config file>``: the sizes, and ``family``, which names
-  ``bench/configs/<family>.py``: the plain reference and the seeded weights;
+  two modules: ``bench/configs/<family>.py``, the plain reference (it imports
+  nothing of the program), with the seeded weights, ``merge_schedule``,
+  ``num_tokens`` and ``frame_flops``; and ``bench/configs/<family>_served.py``,
+  which holds every import of the program for the family: ``model_config``
+  (the program's model config for the sizes) and ``param_specs`` (the layout
+  the seeded weights are checked against);
 * ``bench/traffic/<traffic>.json``: the traffic mix;
 * ``bench/limits/<workload>.json``: the limit of each number compared;
 * ``bench/metrics/<metric>.py``: a reader with ``read(run) -> float | None``.
+
+Of the sizes the harness reads itself only ``family``, ``dtype``,
+``image_size`` (the frames), ``num_layers``, ``num_classes`` and
+``tome.schedule``; every sizes file carries them. The rest is the family's.
 
 A tick is one ``FleetRuntime.run`` of the cell's fleet, one frame per
 stream, timed from the call until every frame's logits are ready.
@@ -69,7 +78,13 @@ class Cell:
 
     @property
     def family(self):
+        """The plain reference of the configuration's family."""
         return load_module(BENCH / "configs" / f"{self.config['family']}.py")
+
+    @property
+    def served(self):
+        """The family's adapter to the program's model."""
+        return load_module(BENCH / "configs" / f"{self.config['family']}_served.py")
 
 
 def load_cell(name: str, bench: dict | None = None) -> Cell:
@@ -147,19 +162,14 @@ def build(cell: Cell, seed: int) -> Served:
     from repro.core import bucketing, engine
     from repro.launch import serve
     from repro.models import param as param_lib
-    from repro.models import vit
     from repro.serving import workload
 
     c, t = cell.config, cell.traffic
     dtype = jnp.dtype(c["dtype"])
-    model_cfg = vit.ViTConfig(
-        img_res=c["image_size"], patch=c["patch_size"], n_layers=c["num_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_heads"], d_ff=c["mlp_dim"],
-        n_classes=c["num_classes"], dtype=dtype,
-        prop_attn=c["tome"]["proportional_attention"])
+    model_cfg = cell.served.model_config(c, dtype)
     wkey, ikey = seed_keys(seed, 2)
     params = cell.family.init_params(c, wkey, dtype)
-    want = param_lib.abstract_params(vit.specs(model_cfg), dtype)
+    want = param_lib.abstract_params(cell.served.param_specs(model_cfg), dtype)
     got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
     if jax.tree.structure(got) != jax.tree.structure(want) or got != want:
         raise ValueError("the seeded weights do not match the served model's layout")
@@ -213,10 +223,9 @@ def served_frames(fs, image_index: int) -> list[tuple]:
 
 
 def tick_flops(cell: Cell, fs) -> float:
-    from flops import frame_flops
     c, fam = cell.config, cell.family
-    n_tok = (c["image_size"] // c["patch_size"]) ** 2 + 1
-    return sum(frame_flops(c, fam.merge_schedule(f.alpha, c["num_layers"], n_tok))
+    n_tok = fam.num_tokens(c)
+    return sum(fam.frame_flops(c, fam.merge_schedule(f.alpha, c["num_layers"], n_tok))
                for f in fs.all_frames)
 
 

@@ -1,6 +1,6 @@
-"""Model step: the operations the traced ticks' frames require (``flops``:
-from each frame's executed merge schedule, padding not counted) over the
-traced window, the chips used and their bf16 peak (%)."""
+"""Model step: the operations the traced ticks' frames require (the
+family's ``frame_flops``, from each frame's executed merge schedule, padding
+not counted) over the traced window, the chips used and their bf16 peak (%)."""
 
 
 def read(run):

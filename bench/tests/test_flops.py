@@ -1,4 +1,4 @@
-"""The FLOP count against a hand count, and the peaks table."""
+"""The ViT family's FLOP count against a hand count, and the peaks table."""
 import json
 
 import pytest
@@ -7,6 +7,7 @@ import flops
 import harness
 
 VIT_L = json.loads((harness.BENCH / "configs" / "vit-l384.json").read_text())
+VIT = harness.load_module(harness.BENCH / "configs" / "vit.py")
 
 
 def test_vit_l384_unpruned_blocks_by_hand():
@@ -14,7 +15,7 @@ def test_vit_l384_unpruned_blocks_by_hand():
     per_block = 8 * n * d * d + 4 * n * n * d + 4 * n * d * f
     embed, head = 2 * 576 * 768 * d, 2 * d * 1000
     assert per_block * 24 == 381_224_583_168          # 381.2 GFLOP in the blocks
-    assert flops.frame_flops(VIT_L, [0] * 24) == embed + per_block * 24 + head
+    assert VIT.frame_flops(VIT_L, [0] * 24) == embed + per_block * 24 + head
 
 
 def test_a_merging_block_counts_tome_scores_and_a_smaller_mlp():
@@ -23,7 +24,7 @@ def test_a_merging_block_counts_tome_scores_and_a_smaller_mlp():
     sched = [r] + [0] * 23
     rest = 23 * (8 * (n - r) * d * d + 4 * (n - r) ** 2 * d + 4 * (n - r) * d * f)
     embed, head = 2 * 576 * 768 * d, 2 * d * 1000
-    assert flops.frame_flops(VIT_L, sched) == embed + first + rest + head
+    assert VIT.frame_flops(VIT_L, sched) == embed + first + rest + head
 
 
 def test_peaks_know_the_v5e_and_refuse_others():
